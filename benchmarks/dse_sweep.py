@@ -26,22 +26,17 @@ the closed-loop request-level scheduler (steady vs overload-with-robustness
 traffic as first-class axes) into ``kind=serving`` rows — per-(hardware x
 scenario) p50/p95/p99 latency, goodput and shed/timeout/retry counters.
 
-The **sharded probe** measures the device-sharded sweep: a subprocess under
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (so the parent's
-numbers keep the real single-device runtime) runs a 96-config grid unsharded
-and sharded over 8 host devices, asserts bitwise equality, and reports
-``sharded_speedup`` into the perf row. Host "devices" are threads over the
-same cores, so the speedup ceiling is ``host_cpus`` — the recorded
-``host_cpus`` makes a 1-core CI runner's ~1x honest rather than alarming.
+The **sharded probe** measures the device-sharded sweep in the same process:
+with two or more devices it runs a 96-config grid unsharded and sharded over
+every device, checks bitwise equality and fault-free telemetry, and reports
+``sharded_speedup`` into the perf row. With one device it is skipped, and the
+script says so. A probe failure fails the script.
 """
 from __future__ import annotations
 
-import json
 import os
-import subprocess
-import sys
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List
 
 from repro.core import (
     OnChipPolicy,
@@ -72,13 +67,11 @@ PLACEMENT_AXES = dict(
 
 # The sharded probe's grid: the perf-gate grid widened by zipf x cores to
 # 96 configs (4 x 3 x 2 x 2 x 2) so the shard partition has enough memo-key
-# groups to spread across 8 devices.
+# groups to spread across every device of a host.
 SHARDED_AXES = dict(
     policies=POLICIES, capacities=CAPACITIES, ways=WAYS,
     zipf_s=(0.8, 1.0), num_cores=(1, 2), seed=0,
 )
-SHARDED_DEVICES = 8
-_PROBE_MARKER = "SHARDED_PROBE_JSON:"
 
 # Serving-scenario slice: the closed-loop request-level scheduler as DSE
 # axes (traffic pattern x robustness policy) over the perf-gate policies.
@@ -231,28 +224,26 @@ def run(profile: bool = False) -> List[Dict]:
     return rows
 
 
-def sharded_probe() -> Dict:
-    """The 96-config grid, unsharded vs sharded over the forced host devices
-    (run this under ``XLA_FLAGS=--xla_force_host_platform_device_count=8``)
-    — asserts bitwise equality, reports the wall-clock ratio."""
-    import jax
-
+def sharded_probe(devices: int) -> Dict:
+    """The 96-config grid, unsharded vs sharded over ``devices`` devices of
+    this process — raises unless bitwise equal, reports the wall-clock ratio."""
     wl = dlrm_rmc2_small(num_tables=TABLES, rows_per_table=ROWS,
                          batch_size=BATCH, num_batches=2)
     base_hw = tpuv6e()
     sweep(wl, base_hw, **SHARDED_AXES)                       # warm
     ref = _best_of(2, lambda: sweep(wl, base_hw, **SHARDED_AXES))
-    sweep(wl, base_hw, devices=SHARDED_DEVICES, **SHARDED_AXES)   # warm
+    sweep(wl, base_hw, devices=devices, **SHARDED_AXES)      # warm
     sh = _best_of(
-        2, lambda: sweep(wl, base_hw, devices=SHARDED_DEVICES, **SHARDED_AXES)
+        2, lambda: sweep(wl, base_hw, devices=devices, **SHARDED_AXES)
     )
     for a, b in zip(ref.entries, sh.entries):
-        assert a.config == b.config
-        mism = a.result.diff(b.result)
-        assert not mism, (a.config.label, mism)
+        if a.config != b.config or a.result.diff(b.result):
+            raise RuntimeError(f"sharded probe: {b.config.label} differs "
+                               f"from unsharded {a.config.label}")
     # The probe runs fault-free: any retry/failover here is a bug in the
     # supervision layer, not runner noise.
-    assert not sh.telemetry.any_faults, sh.telemetry.to_dict()
+    if sh.telemetry.any_faults:
+        raise RuntimeError(f"sharded probe: faults {sh.telemetry.to_dict()}")
     return {
         "sharded_fault_telemetry": sh.telemetry.brief(),
         "sharded_configs": sh.num_configs,
@@ -263,67 +254,33 @@ def sharded_probe() -> Dict:
         "sharded_sweep_s": sh.wall_seconds,
         "sharded_speedup": ref.wall_seconds / max(sh.wall_seconds, 1e-9),
         "sharded_per_config_ms": sh.wall_seconds / sh.num_configs * 1e3,
-        "host_devices": len(jax.devices()),
     }
-
-
-def run_sharded_subprocess() -> Optional[Dict]:
-    """Run the sharded probe in a child process with 8 forced host devices —
-    XLA device topology is fixed at backend init, so the parent process
-    (whose headline numbers must reflect the real device) cannot host it.
-    Returns None (with a note) if the child fails; the benchmark's other
-    rows still save."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={SHARDED_DEVICES}"
-    ).strip()
-    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (os.path.join(repo_root, "src"),
-                    env.get("PYTHONPATH", "")) if p
-    )
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.dse_sweep", "--sharded-probe"],
-            cwd=repo_root, env=env, capture_output=True, text=True,
-            timeout=1800,
-        )
-    except (OSError, subprocess.TimeoutExpired) as exc:
-        print(f"sharded probe failed to run: {exc}", file=sys.stderr)
-        return None
-    for line in proc.stdout.splitlines():
-        if line.startswith(_PROBE_MARKER):
-            return json.loads(line[len(_PROBE_MARKER):])
-    print("sharded probe produced no result:\n"
-          f"{proc.stdout}\n{proc.stderr}", file=sys.stderr)
-    return None
 
 
 if __name__ == "__main__":
     import argparse
 
+    import jax
+
     from benchmarks import common
+    from repro.launch.compile_cache import enable_compile_cache
 
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--profile", action="store_true",
                     help="add a per-stage wall-time breakdown to the perf row")
     ap.add_argument("--no-sharded", action="store_true",
-                    help="skip the sharded-sweep probe subprocess")
-    ap.add_argument("--sharded-probe", action="store_true",
-                    help=argparse.SUPPRESS)   # internal: child-process mode
+                    help="skip the sharded-sweep probe")
     args = ap.parse_args()
-
-    if args.sharded_probe:
-        print(_PROBE_MARKER + json.dumps(sharded_probe()))
-        sys.exit(0)
+    enable_compile_cache()
 
     bench_rows = run(profile=args.profile)
     perf = next(r for r in bench_rows if r["kind"] == "perf")
-    if not args.no_sharded:
-        probe = run_sharded_subprocess()
-        if probe is not None:
-            perf.update(probe)
+    n_devices = jax.device_count()
+    if not args.no_sharded and n_devices >= 2:
+        perf.update(sharded_probe(n_devices))
+    elif not args.no_sharded:
+        print(f"sharded probe skipped: {n_devices} device "
+              f"({jax.devices()[0].platform}); it needs 2 or more")
     path = common.save_rows("BENCH_sweep", bench_rows, repo_root=True)
     print(f"saved {path}")
     print(f"configs={perf['configs']} sweep_s={perf['sweep_s']:.2f} "
@@ -336,7 +293,7 @@ if __name__ == "__main__":
           f"@ {perf['best_serving_p99_config']}")
     if "sharded_speedup" in perf:
         print(f"sharded: {perf['sharded_configs']} configs on "
-              f"{perf['sharded_device_count']} host devices "
+              f"{perf['sharded_device_count']} devices "
               f"(host_cpus={perf['host_cpus']}) "
               f"speedup={perf['sharded_speedup']:.2f}x bitexact=True")
     if args.profile:

@@ -3,7 +3,7 @@
 Launch with ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (the
 dse-scale CI job does): tier-1 tests deliberately see the real single
 device, so the genuinely multi-device paths — the shard mesh, the
-``shard_map_compat`` psum gather check, per-shard ``jax.default_device``
+``jax.shard_map`` psum gather check, per-shard ``jax.default_device``
 pinning — are exercised here.
 
 Three gates, every one an acceptance criterion of the scaling PR:

@@ -21,23 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-# jax >= 0.6 exposes shard_map at the top level (replication check spelled
-# `check_vma`); older releases keep it in jax.experimental with `check_rep`.
-# Exported as ``shard_map_compat`` so other distributed layers (the sharded
-# DSE sweep's cross-device gather) reuse ONE version shim.
-if hasattr(jax, "shard_map"):
-    def shard_map_compat(body, mesh, in_specs, out_specs):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def shard_map_compat(body, mesh, in_specs, out_specs):
-        return _experimental_shard_map(body, mesh=mesh, in_specs=in_specs,
-                                       out_specs=out_specs, check_rep=False)
-
-_shard_map = shard_map_compat    # internal alias (tests patch/import this)
-
 
 def _own_chunk(x_loc, w_loc, c, n_chunks):
     nc = w_loc.shape[-1] // n_chunks
@@ -70,12 +53,13 @@ def ring_matmul(
         gathered = jnp.take(gathered, order, axis=0)
         return jnp.concatenate(jnp.split(gathered, n, axis=0), axis=-1)[0]
 
-    # replication is established by the final gather (check disabled in shim)
-    return _shard_map(
+    # replication is established by the final gather (check disabled)
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(*(None,) * (x.ndim - 1), axis), P(axis, None)),
         out_specs=P(*(None,) * (x.ndim - 1), None),
+        check_vma=False,
     )(x, w)
 
 
@@ -85,10 +69,11 @@ def psum_matmul(x, w, mesh, axis="model"):
     def body(x_loc, w_loc):
         return jax.lax.psum(x_loc @ w_loc, axis)
 
-    # psum output is replicated by construction (check disabled in shim)
-    return _shard_map(
+    # psum output is replicated by construction (check disabled)
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(*(None,) * (x.ndim - 1), axis), P(axis, None)),
         out_specs=P(*(None,) * (x.ndim - 1), None),
+        check_vma=False,
     )(x, w)

@@ -28,9 +28,9 @@ batch composition — so scaling out is a pure partitioning problem:
     (bugs, not infrastructure) raise ``ShardEvaluationError`` with shard/
     device/key-group context, carrying all completed sibling-shard results
     so surviving work is never discarded.
-  * **Cross-device gather check** through the ``shard_map_compat`` version
-    shim (the same one the collective matmul uses): each shard contributes
-    its key count on its mesh position and a psum must see every shard —
+  * **Cross-device gather check** through ``jax.shard_map``: each shard
+    contributes its key count on its mesh position and a psum must see
+    every shard —
     a cheap end-to-end assertion that the mesh actually spans the devices
     the plan claims (validated on CPU CI under
     ``XLA_FLAGS=--xla_force_host_platform_device_count=8``).
@@ -59,7 +59,6 @@ from ..core.faults import (
     backoff_seconds,
     classify_exception,
 )
-from .collective_matmul import shard_map_compat
 
 __all__ = [
     "ShardPlan",
@@ -385,8 +384,8 @@ def evaluate_sharded(
 
 
 def shard_key_totals(counts: Sequence[int], plan: ShardPlan) -> int:
-    """psum the per-shard key counts across the plan's devices through the
-    ``shard_map_compat`` shim. With repeated devices (oversubscribed
+    """psum the per-shard key counts across the plan's devices through
+    ``jax.shard_map``. With repeated devices (oversubscribed
     shards) the mesh would alias, so the collective runs over the distinct
     device set with per-device subtotals — the returned total is the same
     either way. Devices that contributed zero keys are left out of the
@@ -416,7 +415,8 @@ def shard_key_totals(counts: Sequence[int], plan: ShardPlan) -> int:
     def body(x):
         return jax.lax.psum(x, "shard")
 
-    fn = shard_map_compat(body, mesh, in_specs=P("shard"), out_specs=P())
+    fn = jax.shard_map(body, mesh=mesh, in_specs=P("shard"), out_specs=P(),
+                       check_vma=False)
     arr = np.asarray(per_dev, dtype=np.int64)
     # body returns the (1,)-shaped replicated total per device.
     return int(np.asarray(fn(arr)).ravel()[0])

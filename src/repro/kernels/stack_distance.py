@@ -12,95 +12,65 @@ updating is one rotate-insert toward MRU, no timestamps.
 This is a deliberately different *shape* of implementation from both the
 ``(tags, meta)`` cache-scan kernel and the analytic engine — agreement across
 the three (and ``GoldenCache``) is therefore meaningful, and is enforced by
-the differential fuzz tests in ``tests/test_cache_stack.py``. Off-TPU the
-kernel runs in interpret mode so CPU CI exercises the exact kernel program.
+the differential fuzz tests in ``tests/test_cache_stack.py``. It shares the
+cache-scan kernel's TPU layout (``kernels/cache_scan.py``: SMEM per-access
+scalars, a sequential tile axis carrying the state, lane-dense outputs).
+Off-TPU the kernel runs in interpret mode so CPU CI exercises the exact
+kernel program.
 
 Outputs: per-access capped distance (int32; hit for W ways iff ``dist < W``
 with ``W <= ways``) and the eviction flag (miss with a full set).
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .cache_scan import access_scalars, lanes_any, run_set_groups, walk_tile
+
 
 def _stack_distance_kernel(
-    num_sets: int,
     ways: int,
-    s_ref,        # (1, L) int32 local set index per access
-    t_ref,        # (1, L) int32 tag per access
-    v_ref,        # (1, L) int32 1 = real access, 0 = padding
-    dist_ref,     # (1, L) int32 out: stack distance, capped at ways
-    evict_ref,    # (1, L) int32 out: eviction performed
-    tags_ref,     # VMEM (num_sets, ways) int32 scratch: recency list, -1 empty
+    x_ref,        # SMEM (3, T) int32 per access: local set, tag, valid
+    dist_ref,     # VMEM (T / 128, 128) int32 out: stack distance, capped
+    evict_ref,    # VMEM (T / 128, 128) int32 out: eviction performed
+    tags_ref,     # VMEM (sets, 1, lanes) int32 scratch: recency list, -1 empty
 ):
-    L = s_ref.shape[1]
-    tags_ref[...] = jnp.full((num_sets, ways), -1, dtype=jnp.int32)
-    way_idx = jax.lax.broadcasted_iota(jnp.int32, (1, ways), 1)
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        tags_ref[...] = jnp.full(tags_ref.shape, -1, jnp.int32)
 
-    def body(i, _):
-        s = s_ref[0, i]
-        tag = t_ref[0, i]
-        valid = v_ref[0, i] != 0
+    lanes = tags_ref.shape[2]
+    way_idx = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
 
-        row = pl.load(tags_ref, (pl.dslice(s, 1), slice(None)))   # (1, W)
-        hit_vec = row == tag
-        found = jnp.any(hit_vec)
+    def access(i):
+        s, tag, valid = access_scalars(x_ref, i)
+
+        row = tags_ref[s]                                   # (1, lanes)
+        hit_vec = (row == tag) & (way_idx < ways)
         # Position of the tag in the recency list = capped stack distance.
-        pos = jnp.sum(
-            jnp.where(hit_vec, way_idx, 0), dtype=jnp.int32
-        )
-        dist = jnp.where(found, pos, jnp.int32(ways))
+        pos = jnp.min(jnp.where(hit_vec, way_idx, lanes), axis=-1,
+                      keepdims=True)
+        found = pos < lanes
+        dist = jnp.where(found, pos, ways)
 
         # Rotate-insert toward MRU: ways [1, limit] take their left
         # neighbour, way 0 takes the tag; ways beyond the hit position (or
-        # everything on a miss, dropping the LRU way) stay put.
-        limit = jnp.where(found, pos, jnp.int32(ways - 1))
-        rolled = jnp.roll(row, 1, axis=1)
+        # everything on a miss, dropping the LRU way) stay put. Padding
+        # lanes lie beyond ``ways - 1 >= limit`` and never change.
+        limit = jnp.where(found, pos, ways - 1)
+        rolled = pltpu.roll(row, 1, 1)
         new_row = jnp.where(
             way_idx == 0, tag, jnp.where(way_idx <= limit, rolled, row)
         )
-        evict = valid & ~found & (row[0, ways - 1] >= 0)
-        new_row = jnp.where(valid, new_row, row)
-        pl.store(tags_ref, (pl.dslice(s, 1), slice(None)), new_row)
+        full = lanes_any((way_idx == ways - 1) & (row >= 0))
+        evict = valid & ~found & full
+        tags_ref[s] = jnp.where(valid, new_row, row)
+        return jnp.where(valid, dist, ways), evict.astype(jnp.int32)
 
-        pl.store(
-            dist_ref, (slice(0, 1), pl.dslice(i, 1)),
-            jnp.where(valid, dist, jnp.int32(ways)).reshape(1, 1),
-        )
-        pl.store(
-            evict_ref, (slice(0, 1), pl.dslice(i, 1)),
-            evict.astype(jnp.int32).reshape(1, 1),
-        )
-        return 0
-
-    jax.lax.fori_loop(0, L, body, 0)
-
-
-@functools.lru_cache(maxsize=None)
-def _build_stack_distance(
-    num_sets: int, ways: int, B: int, L: int, interpret: bool
-):
-    """Memoized pallas_call per (geometry, batch shape) — bucketed sweeps
-    re-dispatch identical shapes, so the kernel closure is built once."""
-    kernel = functools.partial(_stack_distance_kernel, num_sets, ways)
-    row = pl.BlockSpec((1, L), lambda b: (b, 0))
-    return pl.pallas_call(
-        kernel,
-        grid=(B,),
-        in_specs=[row, row, row],
-        out_specs=[row, row],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, L), jnp.int32),
-            jax.ShapeDtypeStruct((B, L), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.VMEM((num_sets, ways), jnp.int32)],
-        interpret=interpret,
-    )
+    walk_tile(access, x_ref, (dist_ref, evict_ref))
 
 
 def stack_distance_groups(
@@ -117,13 +87,8 @@ def stack_distance_groups(
     ``ways`` (hit for W-way LRU iff ``dist < W``) and bool eviction flags.
     ``interpret=None`` auto-selects interpret mode off-TPU.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    B, L = sets.shape
-    call = _build_stack_distance(
-        int(num_sets), int(ways), int(B), int(L), bool(interpret)
-    )
-    dist, evict = call(
-        sets.astype(jnp.int32), tags.astype(jnp.int32), valid.astype(jnp.int32)
+    dist, evict = run_set_groups(
+        _stack_distance_kernel, (int(ways),), 1, sets, tags, valid,
+        num_sets, ways, interpret,
     )
     return dist, evict.astype(bool)
