@@ -56,8 +56,7 @@ WAYS = (8, 16)
 ZIPF = 1.0
 N_INDEPENDENT_SAMPLE = 6
 
-# The placement-axes slice grid: shared with scripts/perf_smoke.py (imported,
-# not copied, so the ratio gate measures exactly what the benchmark reports).
+# The placement-axes slice grid.
 PLACEMENT_TABLES = 6
 PLACEMENT_AXES = dict(
     policies=("spm", "lru"), zipf_s=ZIPF, seed=0,

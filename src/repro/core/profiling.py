@@ -5,9 +5,18 @@ trace generation, on-chip classification, the cache scan itself, DRAM
 timing, or host<->device synchronization. This module is the single owner
 of that attribution: hot-path stages wrap themselves in ``stage(name)`` and
 a profiling session (``collect()``) accumulates exclusive wall time per
-stage. When no session is active the wrappers cost one global read and a
-``None`` check — nothing is timed, so ``simulate()``/``sweep()`` keep their
-normal performance.
+stage. When no session is active nothing is timed: the wrappers cost the
+span below, one global read and a ``None`` check, so ``simulate()``/
+``sweep()`` keep their normal performance.
+
+Every ``stage`` also writes a host span named ``stage.<name>``
+(``SPAN_PREFIX``) through ``jax.profiler.TraceAnnotation``, with or without
+a session. Where no ``jax.profiler`` trace is open the span costs about a
+microsecond; where one is open, from TensorBoard or ``jax.profiler.trace``,
+each stage appears on the thread that ran it, on the profiler's clock,
+beside the device's programs. Such a trace never opens a session and
+forces no ``block_until_ready``, so it shows the program running
+asynchronously, as it does unprofiled.
 
 Stages nest: time spent inside an inner ``stage`` is attributed to the
 inner stage only (exclusive accounting), so ``classify`` does not
@@ -24,6 +33,8 @@ Canonical stage names used by the memory pipeline:
   * ``host_sync``   — blocking device->host result extraction (np.asarray
                       of JAX arrays; the cost the device-resident pipeline
                       is designed to keep out of the inner loop)
+  * ``stack_distance`` — the analytic LRU stack-distance pass (core.memory.stack)
+  * ``translate``   — analytic TLB classification of the miss-page stream
   * ``fault_wait``  — fault-tolerance stalls: retry backoff sleeps in the
                       sharded sweep's workers (core.faults). Separated out
                       so an injected-fault run's breakdown shows recovery
@@ -36,7 +47,11 @@ import time
 from contextlib import contextmanager
 from typing import Dict, Iterator, List, Optional
 
-__all__ = ["stage", "collect", "is_active", "StageProfile"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["stage", "collect", "is_active", "StageProfile", "SPAN_PREFIX"]
+
+SPAN_PREFIX = "stage."
 
 
 class StageProfile:
@@ -82,28 +97,32 @@ def is_active() -> bool:
     their own stage (``jax.block_until_ready``) so that asynchronous-dispatch
     wait time is attributed to the compute stage, not to the ``host_sync``
     extraction that would otherwise absorb it. Never true in production, so
-    the extra synchronization only exists while profiling.
+    the extra synchronization only exists while profiling. An open
+    ``jax.profiler`` trace does not make it true.
     """
     return _active is not None
 
 
 @contextmanager
 def stage(name: str) -> Iterator[None]:
-    """Attribute the enclosed wall time to ``name`` (exclusive of children)."""
-    prof = _active
-    if prof is None:
-        yield
-        return
-    stack = prof._stack()
-    stack.append([name, time.perf_counter(), 0.0])
-    try:
-        yield
-    finally:
-        frame = stack.pop()
-        elapsed = time.perf_counter() - frame[1]
-        prof._add(name, elapsed - frame[2])
-        if stack:
-            stack[-1][2] += elapsed
+    """Attribute the enclosed wall time to ``name`` (exclusive of children)
+    in an open session, and mark it as the span ``stage.<name>`` in any
+    open profiler trace."""
+    with TraceAnnotation(SPAN_PREFIX + name):
+        prof = _active
+        if prof is None:
+            yield
+            return
+        stack = prof._stack()
+        stack.append([name, time.perf_counter(), 0.0])
+        try:
+            yield
+        finally:
+            frame = stack.pop()
+            elapsed = time.perf_counter() - frame[1]
+            prof._add(name, elapsed - frame[2])
+            if stack:
+                stack[-1][2] += elapsed
 
 
 @contextmanager

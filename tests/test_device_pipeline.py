@@ -197,3 +197,41 @@ def test_profiling_disabled_reports_nothing():
     with profiling.collect() as prof:
         pass
     assert prof.breakdown() == {}
+
+
+def test_profiler_trace_shows_stage_spans_without_a_session(tmp_path, monkeypatch):
+    """An open ``jax.profiler`` trace holds a ``stage.<name>`` host span for
+    every stage a session would time, opens no session and so makes no
+    stage block on the device; a session inside the trace times the same
+    stages as one outside it."""
+    import glob
+
+    import jax
+    from repro.core.memory import cache, dram, rrip, stack
+
+    wl = dlrm_rmc2_small(num_tables=2, rows_per_table=400, batch_size=4,
+                         num_batches=2)
+    hw = tpuv6e().with_policy("lru", capacity_bytes=1 << 15)
+    gates = []
+    for mod in (cache, dram, rrip, stack):
+        monkeypatch.setattr(mod, "_profiling_active",
+                            lambda: gates.append(profiling.is_active()) or gates[-1])
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=options):
+        simulate(wl, hw, seed=0, zipf_s=0.9)
+        traced_gates = list(gates)
+        with profiling.collect() as traced:
+            simulate(wl, hw, seed=1, zipf_s=0.9)
+    with profiling.collect() as untraced:
+        simulate(wl, hw, seed=1, zipf_s=0.9)
+    assert traced_gates and not any(traced_gates)
+    assert all(gates[len(traced_gates):])
+
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    names = {e.name for plane in jax.profiler.ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:") for line in plane.lines for e in line.events}
+    spans = {n[len(profiling.SPAN_PREFIX):] for n in names
+             if n.startswith(profiling.SPAN_PREFIX)}
+    assert {"trace_gen", "classify", "stack_distance", "dram"} <= spans
+    assert spans == set(traced.seconds) == set(untraced.seconds)
