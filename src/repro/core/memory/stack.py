@@ -37,7 +37,15 @@ Three executions of the same math, all bit-exact against ``GoldenCache``
     tested against.
   * ``stack_distances_jnp``  — jitted jnp port, device-resident for TPU-side
     pipelines (padded to a bucketed length; num_sets is a traced scalar so
-    one compilation serves every geometry of a length bucket).
+    one compilation serves every geometry of a length bucket). On a TPU v5e
+    a gather or scatter of unknown indices costs 5-9 ns an element, a
+    payload-carrying sort 1.5-2.5 ns (PERF.md §5), so the port moves the
+    trace between orders with sorts alone: (set, time), (line, time), rank,
+    back to (set, time) and to time order, five sorts whose payloads carry
+    what the next order needs. Each half of the inversion count runs in the
+    order that makes it a block-local compare (same bucket in rank order,
+    other buckets in position order), and the (chunk, bucket) table is built
+    and read through one-hot matmuls, not a scatter-add and a gather.
   * ``kernels/stack_distance.py`` — Pallas kernel variant of the distance
     pass (``cache_backend="stack_pallas"``), VMEM-resident recency state.
 
@@ -218,83 +226,129 @@ def _prev_larger_in_blocks_jnp(V: jax.Array, tri: jax.Array) -> jax.Array:
     (slab, bs, bs) boolean intermediates stay bounded under jit too)."""
     G, bs = V.shape
     slab = max(1, _SLAB_ELEMS // (bs * bs))
-    if slab >= G:
-        return jnp.sum((V[:, :, None] > V[:, None, :]) & tri, axis=1,
-                       dtype=jnp.int32)
     parts = [
-        jnp.sum((V[lo:lo + slab, :, None] > V[lo:lo + slab, None, :]) & tri,
-                axis=1, dtype=jnp.int32)
-        for lo in range(0, G, slab)
+        jnp.sum((W[:, :, None] > W[:, None, :]) & tri, axis=1, dtype=jnp.int32)
+        for W in (V[lo:lo + slab] for lo in range(0, G, slab))
     ]
     return jnp.concatenate(parts, axis=0)
 
 
-def _inv_prev_larger_jnp(rk: jax.Array, bs: int) -> jax.Array:
+def _same_bucket_jnp(p: jax.Array, bs: int) -> jax.Array:
+    """Same-bucket half of the inversion count, in rank order.
+
+    ``p[j]`` is the position of rank ``j``. Returns, for each rank ``j``,
+    ``#{j' > j in j's bucket of bs ranks : p[j'] < p[j]}`` — the pairs the
+    numpy twin counts after sorting by (bucket, time), read here straight off
+    the rank order's rows: a row reversed and negated turns "later and
+    smaller" into "earlier and larger"."""
+    N = p.shape[0]
+    tri = jnp.arange(bs)[:, None] < jnp.arange(bs)[None, :]
+    rows = (N - 1 - p.reshape(N // bs, bs))[:, ::-1]
+    return _prev_larger_in_blocks_jnp(rows, tri)[:, ::-1].reshape(-1)
+
+
+# float32 holds every integer below 2**24 exactly: the largest (chunk,
+# bucket) table entry a one-hot matmul reads in one piece.
+_F32_EXACT = 1 << 24
+
+
+def _cross_bucket_jnp(rk: jax.Array, bs: int) -> jax.Array:
+    """Cross-bucket half of the inversion count, in position order.
+
+    Returns ``#{s' < s : rk[s'] // bs > rk[s] // bs}`` for a permutation
+    ``rk``: earlier chunks of bs positions through a (chunk, bucket) table,
+    the element's own chunk through a block-local compare. The table is
+    built and read on the MXU, with no scatter or gather: a bucket splits
+    into (hi, lo); a chunk's histogram is the product of its one-hot hi and
+    lo matrices, and each position reads its entry through a one-hot hi
+    product and a select over lo. Each output sums one nonzero product, an
+    integer below 2**24, so float32 at HIGHEST precision holds it exactly."""
     N = rk.shape[0]
     G = N // bs
-    g = rk // bs
-    ordg = jnp.argsort(g)                          # stable: (bucket, time)
-    V = rk[ordg].reshape(G, bs)
+    g = (rk // bs).reshape(G, bs)                  # bucket per position
+    L = 1 << ((G.bit_length() - 1) // 2)
+    H = G // L
+    hi = (g // L)[:, :, None] == jnp.arange(H, dtype=jnp.int32)
+    lo = (g % L)[:, :, None] == jnp.arange(L, dtype=jnp.int32)
+    hist = jnp.einsum(
+        "cbh,cbl->chl", hi.astype(jnp.bfloat16), lo.astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32,
+    ).astype(jnp.int32).reshape(G, G)
+    before = jnp.cumsum(hist, axis=0) - hist       # chunks before c
+    higher = jnp.cumsum(before[:, ::-1], axis=1)[:, ::-1] - before
+    # Entries reach N - bs: past float32's exact range, read 16 bits a time.
+    pieces = ([(higher, 1)] if N <= _F32_EXACT
+              else [(higher >> 16, 1 << 16), (higher & 0xFFFF, 1)])
+    earlier = 0
+    for piece, scale in pieces:
+        row = jnp.einsum(
+            "cbh,chl->cbl", hi.astype(jnp.float32),
+            piece.reshape(G, H, L).astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        earlier = earlier + scale * jnp.sum(
+            jnp.where(lo, row, 0.0), axis=-1
+        ).astype(jnp.int32)
     tri = jnp.arange(bs)[:, None] < jnp.arange(bs)[None, :]
-    cnt = jnp.zeros(N, dtype=jnp.int32).at[ordg].set(
-        _prev_larger_in_blocks_jnp(V, tri).reshape(-1)
-    )
-    NC = N // bs
-    rowflat = jnp.repeat(jnp.arange(NC, dtype=jnp.int32), bs) * G + g
-    hist = jnp.zeros((NC * G,), dtype=jnp.int32).at[rowflat].add(1)
-    hist = hist.reshape(NC, G)
-    before = jnp.cumsum(hist, axis=0) - hist
-    suf = jnp.cumsum(before[:, ::-1], axis=1)[:, ::-1] - before
-    cnt = cnt + suf.reshape(-1)[rowflat]
-    Gt = g.reshape(NC, bs)
-    cnt = cnt + _prev_larger_in_blocks_jnp(Gt, tri).reshape(-1)
-    return cnt
+    return (earlier + _prev_larger_in_blocks_jnp(g, tri)).reshape(-1)
 
 
 @functools.partial(jax.jit, static_argnames=("bs",))
 def _stack_pass_jnp(lines: jax.Array, num_sets: jax.Array, n_real: jax.Array,
                     bs: int):
     """Padded device pass; ``num_sets``/``n_real`` are traced scalars so one
-    compilation serves every geometry of a length bucket."""
+    compilation serves every geometry of a length bucket.
+
+    The numpy twin's math, with every reordering a payload-carrying sort:
+    (set, time) order, then (line, time) order, then rank order, then back
+    to (set, time) and time order. No gather or scatter is left: on a TPU
+    one of unknown indices costs several times a sort that moves the same
+    array with its payloads."""
     N = lines.shape[0]
     idx = jnp.arange(N, dtype=jnp.int32)
+    # Padding gets the set past the last, so it also fills the last
+    # positions of the (set, time) order: ``real`` holds in both orders.
     real = idx < n_real
     set_idx = jnp.where(real, lines % num_sets, num_sets)
 
-    order = jnp.argsort(jnp.where(real, lines, _BIG_I32))
-    ls = lines[order]
-    same = jnp.concatenate(
-        [jnp.zeros(1, bool), (ls[1:] == ls[:-1]) & real[order][1:]]
-    )
-    prev = jnp.full(N, -1, dtype=jnp.int32).at[order].set(
-        jnp.where(
-            same, jnp.concatenate([jnp.zeros(1, jnp.int32), order[:-1]]), -1
-        )
-    )
-
-    order2 = jnp.argsort(set_idx)                  # stable: (set, time)
-    ss = set_idx[order2]
+    # (set, time) order: position s holds a set, a line and their time.
+    ss, ls, ts = jax.lax.sort((set_idx, lines, idx), num_keys=1,
+                              is_stable=True)
     start = jnp.concatenate([jnp.ones(1, bool), ss[1:] != ss[:-1]])
     grp = jax.lax.cummax(jnp.where(start, idx, 0))
-    r = jnp.empty(N, dtype=jnp.int32).at[order2].set(idx - grp)
 
-    valid = prev >= 0
-    win = jnp.where(valid, r - r[jnp.maximum(prev, 0)] - 1, 0)
-
-    o1 = jnp.argsort(prev)
-    p = o1[jnp.argsort(set_idx[o1])]
-    rk = jnp.empty(N, dtype=jnp.int32).at[p].set(idx)
-    T = jnp.empty(N, dtype=jnp.int32).at[order2].set(
-        _inv_prev_larger_jnp(rk[order2], bs)
+    # (line, time) order: each access follows its line's previous access,
+    # so the window ``win`` is a difference of (set, time) positions.
+    ll, sl, gl = jax.lax.sort((jnp.where(real, ls, _BIG_I32), idx, grp),
+                              num_keys=1, is_stable=True)
+    same = jnp.concatenate(
+        [jnp.zeros(1, bool), (ll[1:] == ll[:-1]) & (sl[1:] < n_real)]
     )
-    dist = jnp.where(valid, win - T, jnp.int32(DIST_COLD))
+    sprev = jnp.concatenate([jnp.zeros(1, jnp.int32), sl[:-1]])
+    win = jnp.where(same, sl - sprev - 1, -1)      # -1: cold
 
-    firsts = (~valid & real)[order2].astype(jnp.int32)
+    # Rank order, the lexicographic (set, prev) rank: positions are
+    # set-major, so a warm access keys on its previous position and a cold
+    # one on its set's first, between the warm keys of its set and those of
+    # the set before. Cold accesses tie, and their order among themselves
+    # moves no warm access's count: a distance is only read for warm ones.
+    key = jnp.where(same, 2 * sprev + 2, 2 * gl + 1)
+    _, p, win = jax.lax.sort((key, sl, win), num_keys=1)
+    part = jnp.where(win >= 0, win - _same_bucket_jnp(p, bs), -1)
+
+    # Back to (set, time) order: rk[s] is the rank at position s.
+    _, rk, part = jax.lax.sort((p, idx, part), num_keys=1)
+    valid = part >= 0
+    dist = jnp.where(valid, part - _cross_bucket_jnp(rk, bs),
+                     jnp.int32(DIST_COLD))
+
+    firsts = (~valid & real).astype(jnp.int32)
     cs = jnp.cumsum(firsts)
     seg_base = jax.lax.cummax(jnp.where(start, cs - firsts, 0))
-    distinct_before = jnp.empty(N, dtype=jnp.int32).at[order2].set(
-        cs - firsts - seg_base
-    )
+    distinct_before = cs - firsts - seg_base
+
+    _, dist, distinct_before = jax.lax.sort((ts, dist, distinct_before),
+                                            num_keys=1)
     return dist, distinct_before
 
 

@@ -10,7 +10,10 @@ distance pass). Likewise ``dram_timing_many`` must equal per-request
 dispatch, including the multi-core contended path.
 """
 import logging
+import re
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
@@ -32,6 +35,11 @@ from repro.core.memory.dram import (
 )
 from repro.core.memory.golden import GoldenCache
 from repro.core.memory.stack import (
+    _block_size,
+    _cross_bucket_jnp,
+    _inv_prev_larger_np,
+    _same_bucket_jnp,
+    _stack_pass_jnp,
     classify_lru_stack_many,
     distance_pass_count,
     stack_distances_jnp,
@@ -76,14 +84,38 @@ def test_stack_bit_exact_property(sets, ways, n, space, seed):
     assert ours.num_evictions == gold.num_evictions
 
 
-def test_stack_jnp_engine_matches_numpy(rng):
+def _uniform(n, space):
+    return lambda rng: rng.integers(0, space, size=n)
+
+
+# (lines from a seeded rng, num_sets): padded buckets (n just over 128 and
+# 4096 leaves n_real < N), degenerate and wide set counts, streams with no
+# reuse or nothing but reuse, and a skewed stream of the benchmark's kind.
+JNP_CASES = {
+    "uniform_sets1": (_uniform(777, 5000), 1),
+    "uniform_sets3": (_uniform(777, 5000), 3),
+    "uniform_sets64": (_uniform(777, 5000), 64),
+    "bucket_edge_129": (_uniform(129, 40), 4),
+    "bucket_edge_4097": (_uniform(4097, 3000), 16),
+    "sets1_long": (_uniform(5000, 700), 1),
+    "sets3_long": (_uniform(5000, 700), 3),
+    "sets1024": (_uniform(5000, 20000), 1024),
+    "sets4096": (_uniform(9000, 50000), 4096),
+    "all_cold": (lambda rng: rng.permutation(3000) * 7, 8),
+    "one_line": (lambda rng: np.full(2000, 12345), 16),
+    "zipf_65536": (lambda rng: rng.zipf(1.1, size=1 << 16) % 200_000, 2048),
+}
+
+
+@pytest.mark.parametrize("case", list(JNP_CASES))
+def test_stack_jnp_engine_matches_numpy(case, rng):
     """The device-resident jnp pass equals the numpy host twin bitwise."""
-    for sets in (1, 3, 64):
-        lines = rng.integers(0, 5000, size=777).astype(np.int32)
-        d_np, b_np = stack_distances_np(lines, sets)
-        d_j, b_j = stack_distances_jnp(lines, sets)
-        assert np.array_equal(d_np, d_j)
-        assert np.array_equal(b_np, b_j)
+    make, sets = JNP_CASES[case]
+    lines = make(rng).astype(np.int32)
+    d_np, b_np = stack_distances_np(lines, sets)
+    d_j, b_j = stack_distances_jnp(lines, sets)
+    assert np.array_equal(d_np, d_j)
+    assert np.array_equal(b_np, b_j)
 
 
 def test_stack_jnp_engine_end_to_end(rng):
@@ -279,8 +311,6 @@ def test_stack_memo_distinguishes_aliasing_views(rng):
 def test_inversion_block_size_keeps_histogram_linear():
     """The radix block grows with n so the (chunk, bucket) histogram stays
     O(n) elements — large traces must not allocate quadratic tables."""
-    from repro.core.memory.stack import _block_size
-
     for n in (1, 100, 46080, 1 << 20, 1 << 24):
         bs = _block_size(n)
         assert bs >= 128 and bs & (bs - 1) == 0
@@ -289,11 +319,47 @@ def test_inversion_block_size_keeps_histogram_linear():
     # and the count stays exact at a non-default block size
     rng = np.random.default_rng(3)
     v = rng.permutation(3000).astype(np.int32)
-    from repro.core.memory.stack import _inv_prev_larger_np
-
     ref = _inv_prev_larger_np(v, bs=128)
     for bs in (256, 512):
         assert np.array_equal(_inv_prev_larger_np(v, bs=bs), ref)
+
+
+@pytest.mark.parametrize("split", [False, True], ids=["whole", "16bit"])
+@pytest.mark.parametrize("N,bs", [(4096, 128), (4096, 256), (1 << 15, 128)])
+def test_jnp_inversion_halves_sum_to_numpy_count(N, bs, split, monkeypatch):
+    """The device pass splits the inversion count across two orders: the
+    same-bucket half in rank order, the cross-bucket half in position
+    order. Together they equal the numpy twin's count at any block size,
+    reading the (chunk, bucket) table whole or, as past 2**24 accesses, in
+    16-bit halves."""
+    if split:
+        monkeypatch.setattr(stack_mod, "_F32_EXACT", N // 2)
+    rk = np.random.default_rng(N + bs).permutation(N).astype(np.int32)
+    p = np.argsort(rk).astype(np.int32)
+    same = np.asarray(_same_bucket_jnp(jnp.asarray(p), bs))[rk]
+    cross = np.asarray(_cross_bucket_jnp(jnp.asarray(rk), bs))
+    assert np.array_equal(same + cross, _inv_prev_larger_np(rk, bs=bs))
+
+
+@pytest.mark.parametrize("log2_n", [12, 21])
+def test_stack_pass_jnp_moves_data_by_sorts(log2_n):
+    """The pass's design, read from its lowered program: five sorts carry
+    the trace between its orders, two one-hot matmuls build and read the
+    inversion count's (chunk, bucket) table, and there is no gather or
+    scatter. Those are the slow primitives on a TPU; this fails on the CPU
+    if one comes back."""
+    N = 1 << log2_n
+    i32 = jax.ShapeDtypeStruct((), jnp.int32)
+    text = jax.jit(
+        lambda lines, sets, n: _stack_pass_jnp(lines, sets, n, _block_size(N))
+    ).lower(jax.ShapeDtypeStruct((N,), jnp.int32), i32, i32).as_text()
+    ops = re.findall(r'"?stablehlo\.(sort|scatter|gather|dot_general)\b', text)
+    assert {op: ops.count(op) for op in set(ops)} == {
+        "sort": 5, "dot_general": 2,
+    }
+    # The table read multiplies float32 counts: exact only at HIGHEST.
+    dots = re.findall(r"stablehlo\.dot_general .*", text)
+    assert sum("precision = [HIGHEST, HIGHEST]" in d for d in dots) == 1
 
 
 def test_stack_rejects_out_of_range_lines():

@@ -8,7 +8,7 @@ HBM. Nothing runs, so these say nothing about results or speed.
 
 Shapes are the cache engine's real buckets on the paper's Table I trace with
 16-set groups: ~8192 groups of length 256 at 128 MB, 64 groups of length
-32768 at 1 MB; and the jnp stack-distance pass at 2^21 accesses.
+32768 at 1 MB; and the jnp stack-distance pass at 2^18 and 2^21 accesses.
 """
 import os
 
@@ -22,6 +22,9 @@ from repro.kernels.cache_scan import cache_scan_groups
 from repro.kernels.stack_distance import stack_distance_groups
 
 BUCKETS = [(8192, 256), (64, 32768)]
+# The stack pass's padded lengths in the benchmark's cells: Command R+'s
+# 1.57M line accesses, and Table I's lane-transformed stream.
+STACK_PASS_LENGTHS = [1 << 18, 1 << 21]
 GROUP_SETS, WAYS = 16, 16
 V5E_HBM_BYTES = 16 * 10**9
 
@@ -79,8 +82,8 @@ def test_stack_distance_compiles_for_v5e(B, L, one_chip, no_persistent_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_stack_pass_jnp_compiles_for_v5e(one_chip, no_persistent_cache):
-    N = 1 << 21
+@pytest.mark.parametrize("N", STACK_PASS_LENGTHS)
+def test_stack_pass_jnp_compiles_for_v5e(N, one_chip, no_persistent_cache):
     compiled = _compile(
         lambda lines, sets, n: _stack_pass_jnp(lines, sets, n, _block_size(N)),
         one_chip, ((N,), jnp.int32), ((), jnp.int32), ((), jnp.int32),
