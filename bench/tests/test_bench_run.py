@@ -26,7 +26,7 @@ def test_unit_equals_reference(make):
     want = reference.simulate(cell.config, cell.grid(), seed)
     assert set(got) == set(want)
     assert check.compare(got, want) == (0, 0.0)
-    assert check.identities(cell.config, cell.grid(), [got]) == 0
+    assert check.identities(cell.config, cell.grid(), [got], [seed]) == 0
 
 
 def test_lm_decode_unit_equals_reference():

@@ -3,8 +3,12 @@
 A cell names a configuration and a traffic mix; the per-layer metrics that
 list the cell (or list no cells) are its metrics in a traced run. Each is
 found by name: ``bench/configs/<config>.json``, ``bench/traffic/<traffic>.json``
-and ``bench/metrics/<metric>.py``. Adding a cell, a configuration, a mix or
-a metric adds files and entries; no existing file changes.
+and ``bench/metrics/<metric>.py``. A configuration's ``workload.kind`` names
+the reference's module for it, ``bench/kinds/<kind>.py``, and its
+``program.workload`` the module that builds the simulator's workload,
+``bench/programs/<constructor>.py``. Adding a cell, a configuration, a mix,
+a metric or a workload kind adds files and entries; no existing file
+changes.
 """
 from __future__ import annotations
 
@@ -19,6 +23,9 @@ import numpy as np
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
+METRICS = BENCH / "metrics"
+KINDS = BENCH / "kinds"
+PROGRAMS = BENCH / "programs"
 UNIT_SEED_LOW = 2**20   # warm-up seeds lie below, the window's at or above
 
 
@@ -48,12 +55,30 @@ class Cell:
         return int(rng.integers(UNIT_SEED_LOW, 2**31))
 
 
-def load_metric(name: str):
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
+def _load(directory: Path, name: str, what: str):
+    """The module ``<directory>/<name>.py``, loaded under a name of its own."""
+    path = directory / f"{name}.py"
+    if not path.is_file():
+        raise LookupError(f"no {what} {name!r}: {path.name} is not in {directory}")
+    spec = importlib.util.spec_from_file_location(f"bench_{directory.name}_{name}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_metric(name: str):
+    """A per-layer metric's reader: ``NEEDS`` and ``read(obs)``."""
+    return _load(METRICS, name, "per-layer metric")
+
+
+def load_kind(name: str):
+    """A workload kind's reference module (see ``reference.kind``)."""
+    return _load(KINDS, name, "workload kind")
+
+
+def load_program(name: str):
+    """A workload constructor's module: ``build(cfg) -> (workload, hardware)``."""
+    return _load(PROGRAMS, name, "workload constructor")
 
 
 def load(name: str) -> Cell:

@@ -43,26 +43,25 @@ def sample(cell, units: int, run_seed: int):
     return unit, [grid[i] for i in sorted(picked)]
 
 
-def identities(cfg: dict, grid: List[dict], answers: List[Dict[tuple, dict]]) -> int:
+def identities(cfg: dict, grid: List[dict], answers: List[Dict[tuple, dict]],
+               unit_seeds: List[int]) -> int:
     """Answers missing, or breaking the accounting every policy keeps: per
-    batch, hits + misses = the trace's line accesses, off-chip reads =
-    misses + matrix lines, DRAM row hits + misses = misses, totals =
-    embedding + matrix cycles."""
-    spec = reference.embedding_spec(cfg)
-    line = cfg["hardware"]["onchip"]["line_bytes"]
-    lines = spec["batch"] * spec["tables"] * spec["lookups"] * \
-        -(-spec["dim"] * spec["dtype_bytes"] // line)
-    mat = reference.matrix_summary(reference.matrix_ops(cfg), cfg["hardware"])
+    batch, hits + misses = the batch's line accesses (the workload kind's
+    count for the unit's seed), off-chip reads = misses + matrix lines, DRAM
+    row hits + misses = misses, totals = embedding + matrix cycles."""
+    kind = reference.kind(cfg)
+    mat = reference.matrix_summary(kind.matrix_ops(cfg), cfg["hardware"])
     bad = 0
-    for unit in answers:
+    for unit, seed in zip(answers, unit_seeds, strict=True):
+        lines = kind.batch_lines(cfg, seed)
         bad += len(grid) - sum(reference.config_key(c) in unit for c in grid)
         for rec in unit.values():
             batches = rec["batches"]
-            bad += len(batches) != spec["num_batches"]
-            for b in batches:
+            bad += len(batches) != len(lines)
+            for b, n in zip(batches, lines):
                 m = b["cache_misses"]
-                bad += (b["cache_hits"] + m != lines
-                        or b["onchip_reads"] != mat["reads"] + lines
+                bad += (b["cache_hits"] + m != n
+                        or b["onchip_reads"] != mat["reads"] + n
                         or b["offchip_reads"] != mat["dram_lines"] + m
                         or b["dram_row_hits"] + b["dram_row_misses"] != m
                         or b["matrix_cycles"] != mat["cycles"]
@@ -101,7 +100,8 @@ def numbers(cell, answers: List[Dict[tuple, dict]], unit_seeds: List[int],
     other answers for the sampled configurations in the program's place
     (the control and the planted faults of ``bench/limits.py``)."""
     limits = cell.traffic["check"]["limits"]
-    out = {"answers_inconsistent": identities(cell.config, cell.grid(), answers)}
+    out = {"answers_inconsistent": identities(cell.config, cell.grid(), answers,
+                                              unit_seeds)}
     if answers:
         unit, configs = sample(cell, len(answers), run_seed)
         want = reference.simulate(cell.config, configs, unit_seeds[unit])

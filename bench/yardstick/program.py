@@ -1,6 +1,8 @@
 """The system under test, driven as its users drive it: one ``sweep()`` per
-unit on a fresh trace. This is the only module of the benchmark that
-imports the simulator (``src/repro``)."""
+unit on a fresh trace. This module and the workload constructors in
+``bench/programs`` are the benchmark's code that drives the simulator
+(``src/repro``); ``run.py`` takes only its stage profiler and compile cache
+from it."""
 from __future__ import annotations
 
 import json
@@ -8,30 +10,11 @@ from typing import Dict, List
 
 import jax
 
-from repro.core import hardware, sweep
-from repro.core import workload as workloads
-from repro.core.lm_mapper import lm_workload
-from repro.models import SHAPES_BY_NAME, get_config
+from repro.core import sweep
 
-from . import reference
+from . import cells, reference
 
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
-
-
-def build(cfg: dict):
-    """``(workload, hardware)`` that the configuration file names."""
-    prog, w = cfg["program"], cfg["workload"]
-    if prog["workload"] == "dlrm_rmc2_small":
-        wl = workloads.dlrm_rmc2_small(
-            num_tables=w["num_tables"], rows_per_table=w["rows_per_table"],
-            dim=w["dim"], lookups=w["lookups"], batch_size=w["batch_size"],
-            num_batches=w["num_batches"])
-    elif prog["workload"] == "lm_workload":
-        wl = lm_workload(get_config(prog["arch"]), SHAPES_BY_NAME[prog["shape"]],
-                         num_batches=w["num_batches"])
-    else:
-        raise ValueError(f"unknown workload constructor {prog['workload']!r}")
-    return wl, getattr(hardware, prog["hardware"])()
 
 
 class Unit:
@@ -40,7 +23,8 @@ class Unit:
     def __init__(self, cell, devices: int):
         self.cell = cell
         self.devices = devices
-        self.workload, self.hardware = build(cell.config)
+        constructor = cells.load_program(cell.config["program"]["workload"])
+        self.workload, self.hardware = constructor.build(cell.config)
         t = cell.traffic
         self.axes = dict(policies=tuple(t["policies"]),
                          capacities=tuple(t["capacities"]),

@@ -5,13 +5,18 @@ Given a configuration file's deployment, a traffic mix and a unit seed, it
 draws the index trace, classifies every line access under each requested
 on-chip configuration, times each batch's misses through the DRAM model and
 assembles the per-batch and summary records that ``SimResult.to_json()``
-prints. Everything is a straight transcription of the documented model:
+prints. What differs from one workload kind to another (the line stream,
+the vector work, the matrix ops) lives in the kind's module,
+``bench/kinds/<workload.kind>.py``; the rest is one straight transcription
+of the documented model:
 
-* trace: Zipf(s) inverse-CDF draw over the table's rows, then one row
-  permutation per table (``numpy.random.default_rng(seed + batch)`` for
-  both, as the simulator's generator does);
-* layout: table ``t`` row ``r`` starts at ``t * table_bytes + r * vector_bytes``
-  and touches ``ceil(vector_bytes / line_bytes)`` consecutive lines;
+* trace (``draw_batch``, ``table_stream``, ``table_batch_lines``, for
+  kinds over equal tables):
+  Zipf(s) inverse-CDF draw over the table's rows, then one row permutation
+  per table (``numpy.random.default_rng(seed + batch)`` for both, as the
+  simulator's generator does); table ``t`` row ``r`` starts at
+  ``t * table_bytes + r * vector_bytes`` and touches
+  ``ceil(vector_bytes / line_bytes)`` consecutive lines;
 * on-chip: ``spm`` never hits; ``lru``/``srrip`` are set-associative caches
   with ChampSim replacement (set = line mod sets), all sets stepped in
   lockstep; ``pinning`` pins the most frequent lines up to capacity
@@ -23,7 +28,7 @@ prints. Everything is a straight transcription of the documented model:
   completion is ``max(bank free + activate if the row is closed, bus free)
   + bus cycles``, all in the precision the configuration states; each batch
   starts from an idle DRAM;
-* batch cycles = max(on-chip streaming, DRAM finish, vector pooling), plus
+* batch cycles = max(on-chip streaming, DRAM finish, the kind's vector work), plus
   the analytic matrix model; energy = counts x per-action energies.
 """
 from __future__ import annotations
@@ -33,62 +38,24 @@ from typing import Dict, List, Sequence
 
 import numpy as np
 
+from . import cells
+
 MAX_RRPV = 3
 
 
 # --------------------------------------------------------------------------
-# Workload description from a configuration file
+# Workload kind
 # --------------------------------------------------------------------------
 
-def embedding_spec(cfg: dict) -> dict:
-    """Table count, rows, vector bytes, lookups per sample and batching."""
-    w = cfg["workload"]
-    if w["kind"] == "dlrm":
-        return dict(tables=w["num_tables"], rows=w["rows_per_table"],
-                    dim=w["dim"], dtype_bytes=w["dtype_bytes"],
-                    lookups=w["lookups"], pooling=w["vector_op"],
-                    batch=w["batch_size"], num_batches=w["num_batches"])
-    if w["kind"] == "lm":
-        a = cfg["architecture"]
-        return dict(tables=1, rows=a["vocab_size"], dim=a["hidden_size"],
-                    dtype_bytes=w["dtype_bytes"], lookups=1, pooling="concat",
-                    batch=w["batch_size"], num_batches=w["num_batches"])
-    raise ValueError(f"unknown workload kind {w['kind']!r}")
-
-
-def matrix_ops(cfg: dict) -> List[tuple]:
-    """``(m, n, k, dtype_bytes, count)`` of every matrix op, in model order."""
-    w = cfg["workload"]
-    if w["kind"] == "dlrm":
-        b, db = w["batch_size"], w["mlp_dtype_bytes"]
-        ops = []
-        d = w["dense_features"]
-        for out in w["bottom_mlp"]:
-            ops.append((b, out, d, db, 1))
-            d = out
-        n_vec = w["num_tables"] + 1
-        ops.append((b * n_vec, n_vec, w["dim"], db, 1))
-        d = n_vec * (n_vec - 1) // 2 + w["dim"]
-        for out in w["top_mlp"]:
-            ops.append((b, out, d, db, 1))
-            d = out
-        return ops
-    # Dense decoder at decode: one token per sequence per step.
-    a = cfg["architecture"]
-    tokens, db = w["batch_size"], w["matrix_dtype_bytes"]
-    d, h, kv, dh = (a["hidden_size"], a["num_attention_heads"],
-                    a["num_key_value_heads"], a["head_dim"])
-    eff = max(int(w["seq_len"] * 0.5), 1)          # causal half of the context
-    layer = [
-        (tokens, h * dh, d), (tokens, kv * dh, d), (tokens, kv * dh, d),
-        (tokens, d, h * dh),
-        (tokens * h, eff, dh), (tokens * h, dh, eff),
-        (tokens, a["intermediate_size"], d), (tokens, a["intermediate_size"], d),
-        (tokens, d, a["intermediate_size"]),
-    ]
-    ops = [(m, n, k, db, a["num_hidden_layers"]) for m, n, k in layer]
-    ops.append((tokens, a["vocab_size"], d, db, 1))
-    return ops
+def kind(cfg: dict):
+    """The module of ``bench/kinds`` that the configuration's
+    ``workload.kind`` names. It gives the unit's line stream
+    (``line_stream``: ``lines``, ``batch``, ``sample`` and ``num_batches``,
+    as ``table_stream`` returns them), each batch's vector work
+    (``vector_work``), the matrix ops (``matrix_ops``) and each batch's
+    line accesses (``batch_lines``); everything else here is the same for
+    every kind."""
+    return cells.load_kind(cfg["workload"]["kind"])
 
 
 # --------------------------------------------------------------------------
@@ -96,7 +63,10 @@ def matrix_ops(cfg: dict) -> List[tuple]:
 # --------------------------------------------------------------------------
 
 def draw_batch(spec: dict, zipf_s: float, seed: int):
-    """``(table_ids, row_ids)`` of one batch, in execution order."""
+    """``(table_ids, row_ids)`` of one batch of ``spec["batch"]`` samples,
+    each looking up ``spec["lookups"]`` rows in each of ``spec["tables"]``
+    tables of ``spec["rows"]`` rows, in execution order: a Zipf(s)
+    inverse-CDF draw of ranks, then one row permutation per table."""
     n = spec["batch"] * spec["tables"] * spec["lookups"]
     rows = spec["rows"]
     rng = np.random.default_rng(seed)
@@ -114,9 +84,10 @@ def draw_batch(spec: dict, zipf_s: float, seed: int):
     return table_ids.reshape(-1).astype(np.int64), row_ids.reshape(-1).astype(np.int64)
 
 
-def line_stream(spec: dict, zipf_s: float, seed: int, line_bytes: int) -> dict:
-    """Every line access of the unit's trace, in order, with its batch and
-    the sample (within its batch) that issued it."""
+def table_stream(spec: dict, zipf_s: float, seed: int, line_bytes: int) -> dict:
+    """Every line access of a unit over equal tables of ``spec["dim"]``-wide
+    vectors, in order, with its batch and the sample (within its batch) that
+    issued it; batch ``b`` is drawn from ``seed + b``."""
     vb = spec["dim"] * spec["dtype_bytes"]
     lpv = -(-vb // line_bytes)
     table_bytes = spec["rows"] * vb
@@ -129,7 +100,15 @@ def line_stream(spec: dict, zipf_s: float, seed: int, line_bytes: int) -> dict:
         batch.append(np.full(start.size * lpv, b, dtype=np.int64))
         sample.append(np.repeat(np.arange(t.size) // per_sample, lpv))
     return dict(lines=np.concatenate(lines), batch=np.concatenate(batch),
-                sample=np.concatenate(sample), lpv=lpv)
+                sample=np.concatenate(sample), lpv=lpv,
+                num_batches=spec["num_batches"])
+
+
+def table_batch_lines(spec: dict, line_bytes: int) -> List[int]:
+    """Line accesses of each batch of ``table_stream``: the same for every
+    batch and seed."""
+    lpv = -(-spec["dim"] * spec["dtype_bytes"] // line_bytes)
+    return [spec["batch"] * spec["tables"] * spec["lookups"] * lpv] * spec["num_batches"]
 
 
 # --------------------------------------------------------------------------
@@ -302,21 +281,16 @@ def simulate(cfg: dict, configs: Sequence[dict], seed: int,
     unit drawn from ``seed``; ``ftype`` is the DRAM cycle precision."""
     hw, energy = cfg["hardware"], cfg["energy"]
     line, clock = hw["onchip"]["line_bytes"], hw["clock_ghz"]
-    spec = embedding_spec(cfg)
-    mat = matrix_summary(matrix_ops(cfg), hw)
-    nb = spec["num_batches"]
-    vb = spec["dim"] * spec["dtype_bytes"]
-    pool_flops = (spec["batch"] * spec["tables"] * max((spec["lookups"] - 1) * spec["dim"], 0)
-                  if spec["pooling"] in ("sum", "mean") else 0)
-    vector_cycles = pool_flops / max(hw["vector_lanes"] * hw["vector_sublanes"], 1)
+    wk = kind(cfg)
+    mat = matrix_summary(wk.matrix_ops(cfg), hw)
     streams: Dict[float, dict] = {}           # line stream of each zipf
     out = {}
     for c in configs:
         z = float(c["zipf_s"])
         if z not in streams:
-            streams[z] = line_stream(spec, z, seed, line)
+            streams[z] = wk.line_stream(cfg, z, seed)
         st = streams[z]
-        lines, batch = st["lines"], st["batch"]
+        lines, batch, nb = st["lines"], st["batch"], st["num_batches"]
         cores = int(c["num_cores"])
         core = st["sample"] % cores
         hits = np.zeros(lines.size, dtype=bool)
@@ -337,9 +311,7 @@ def simulate(cfg: dict, configs: Sequence[dict], seed: int,
             onchip_cycles = max(
                 int((in_b & (core == k)).sum()) * line / max(hw["onchip"]["read_bw_bytes_per_cycle"], 1)
                 + hw["onchip"]["latency_cycles"] for k in range(cores))
-            lookups = np.bincount(core[in_b], minlength=cores) // st["lpv"]
-            vec = max(vector_cycles * lookups[k] / max(lookups.sum(), 1) for k in range(cores)) \
-                if cores > 1 else vector_cycles
+            vector_ops, vec = wk.vector_work(cfg, st, in_b, core, cores)
             emb = max(onchip_cycles, finish, vec)
             batches.append(dict(
                 batch_index=b,
@@ -349,7 +321,7 @@ def simulate(cfg: dict, configs: Sequence[dict], seed: int,
                 onchip_reads=mat["reads"] + reads,
                 onchip_writes=mat["writes"] + miss_n + (preload if b == 0 else 0),
                 offchip_reads=mat["dram_lines"] + miss_n,
-                vector_ops=pool_flops,
+                vector_ops=vector_ops,
                 cache_hits=hit_n, cache_misses=miss_n,
                 dram_row_hits=row_hits, dram_row_misses=int(misses.size) - row_hits,
                 tlb_hits=0, tlb_misses=0, tlb_walks=0, translation_cycles=0.0,
